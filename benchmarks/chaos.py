@@ -11,7 +11,9 @@ than silently merged.  Torn/flip cells pair the mangle with a later
 crash (``site:torn:1,site:crash_after:2``) so the resume path actually
 *reads* the corrupt chunk instead of the in-memory copy.
 
-Serving cells run in-process: a federation of store entries is poisoned
+The children run with ``JAX_PLATFORMS=cpu`` (rows say ``platform:
+cpu``): they measure durability, not a device, and the parent process may
+already hold the chip.  Serving cells run in-process: a federation of store entries is poisoned
 one hash at a time (bit flip, vanished entry dir, injected transient
 I/O) and the rows assert the poisoned hash answers a structured 503
 with a per-hash reason while every healthy hash keeps serving 200 — and
@@ -45,6 +47,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 EPS = 0.4
 RHO = 0.999
+CHILD_PLATFORM = "cpu"
 
 # site -> applicable kinds.  Kinds with no surface at a site (torn at a
 # lock transition — nothing is mangle-able there) are exercised where
@@ -141,7 +144,10 @@ def child_main(mode: str, root: str, smoke: bool) -> None:
 
 def _spawn(mode: str, root: str, smoke: bool,
            fault_spec: str = "") -> tuple[int, float, str]:
-    env = dict(os.environ,
+    # JAX_PLATFORMS=cpu: the children measure durability, not a device,
+    # and the parent (benchmarks.run runs every suite in one process) may
+    # already hold the chip, which a child could not then reach
+    env = dict(os.environ, JAX_PLATFORMS=CHILD_PLATFORM,
                PYTHONPATH=os.path.join(REPO, "src")
                + os.pathsep + os.environ.get("PYTHONPATH", ""))
     env.pop(faults.ENV_VAR, None)
@@ -261,6 +267,7 @@ def _durability_rows(smoke: bool, work: str) -> list[dict]:
                 raise RuntimeError(f"gc recovery left chunks: {left}")
         rows.append(dict(
             bench="chaos", site=site, kind=kind, child=child,
+            platform=CHILD_PLATFORM,
             faults=fault_spec, crashed=crashed, faulted_rc=faulted_rc,
             recovered_bitwise=True,
             quarantined=_count_quarantined(root),

@@ -13,9 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import gain_dispatch
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.gain import gain_family_stats, gain_matvec
+from repro.kernels.gain import gain_family_stats
 from repro.kernels.ssd_scan import ssd_chunk_tiles
 
 
@@ -33,13 +34,15 @@ def run(smoke: bool = False) -> list[dict]:
     rng = np.random.default_rng(0)
     rows = []
 
-    # gain kernel: the paper's O(Tn) agent-side computation
+    # single-agent eq.-15 gain: the paper's O(Tn) agent-side computation,
+    # on the family kernel as a one-agent fleet
     T, n = (256, 256) if smoke else (4096, 2048)
     phi = jnp.asarray(rng.normal(size=(T, n)).astype(np.float32))
     g = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
-    got, us = _time(lambda: gain_matvec(phi, g))
-    want = ref.gain_matvec_ref(phi, g)
-    err = float(jnp.max(jnp.abs(got - want)))
+    got, us = _time(lambda: gain_dispatch.practical_gain(
+        g, phi, 0.5, backend="pallas"))
+    want = ref.practical_gain_ref(phi, g, 0.5)
+    err = float(jnp.abs(got - want))
     rows.append(dict(bench="kernel_gain", shape=f"T{T}xn{n}", us_per_call=us,
                      gflop_per_call=2 * T * n / 1e9, max_abs_err=err))
 

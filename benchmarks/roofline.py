@@ -28,8 +28,9 @@ GAIN_SHAPES = {
 
 
 def gain_kernel_rows() -> list[dict]:
-    """Analytic roofline terms for the single-agent matvec kernel and the
-    batched-agent family kernel the fused sweep step dispatches.
+    """Analytic roofline terms for the single-agent gain (the family kernel
+    at m = 1) and the batched-agent family kernel the fused sweep step
+    dispatches.
 
     FLOPs are exact from the kernel definitions (repro/kernels/gain.py).
     HBM traffic follows the BlockSpec index maps: a block re-streams every
@@ -46,7 +47,7 @@ def gain_kernel_rows() -> list[dict]:
     s = GAIN_SHAPES["kernel_gain"]
     T, n = s["T"], s["n"]
     flops = 2.0 * T * n
-    traffic = 4.0 * (T * n + n + T)          # phi + g read, proj written
+    traffic = 4.0 * (T * n + n + 2)          # phi + g read, stats written
     rows.append(_gain_row("kernel_gain", f"T{T}xn{n}", flops, traffic))
 
     from repro.kernels.gain import BLOCK_M, FAMILY_BLOCK_T

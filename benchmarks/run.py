@@ -53,6 +53,7 @@ from benchmarks import (
     theorem1_bound,
 )
 from benchmarks.common import save_rows
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig2": fig2_grid_tradeoff,
@@ -143,6 +144,7 @@ def main() -> None:
     else:
         names = only if only else list(SUITES)
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name in names:

@@ -1,47 +1,38 @@
 """Sweep-throughput frontier: grid size x device count, plus env-family scale.
 
-Two suites, both on the device-sharded, memory-streaming engine (ISSUE 2):
+Two suites on the device-sharded, memory-streaming engine, both run in
+this process on the devices JAX sees — one process per chip, since a
+child process cannot reach a chip its parent already holds:
 
-* ``device_frontier`` — the same flattened grid executed on 1/2/4/8 host
-  devices (``XLA_FLAGS=--xla_force_host_platform_device_count``, one
-  subprocess per count since the device count locks at first jax init):
-  runs/s with the run axis shard_map'd over ``launch.mesh.make_sweep_mesh``.
-  On this 2-core container the frontier saturates at 2 devices — the JSON
-  records whatever the hardware gives; on a real multi-chip host the same
-  code is the scaling curve.
+* ``device_frontier`` — the same flattened grid executed over meshes of
+  d = 1, 2, 4, 8 devices (``launch.mesh.make_sweep_mesh(d)``, a prefix of
+  the real devices; counts above ``jax.device_count()`` are skipped):
+  runs/s with the run axis shard_map'd over the mesh.
 * ``env_family`` — >= 64 random garnet MDP instances as the engine's
-  ``env_sets`` grid axis: one jitted call sweeps the whole family
-  (per-instance exact terms included), demonstrating the fleet-of-
-  environments axis at a scale the unsharded full-trace engine could not
-  hold in memory.
+  ``env_sets`` grid axis, sharded over every device: one jitted call
+  sweeps the whole family (per-instance exact terms included).
 
-Timings separate compile (first call) from steady-state execution.
+Timings separate compile (first call) from steady-state execution, and
+every row names the platform and device kind it ran on.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-DEVICE_COUNTS = (1, 2, 4, 8)
-
-_CODE = r"""
-import json, sys, time
-import jax, jax.numpy as jnp, numpy as np
 from repro.core.algorithm1 import ParamSampler
 from repro.envs import GridWorld, family_sampler_fn, garnet_env_family
 from repro.experiments import SweepSpec, run_sweep
 from repro.launch.mesh import make_sweep_mesh
 
-cfg = json.loads(sys.argv[1])
-mesh = make_sweep_mesh()
+DEVICE_COUNTS = (1, 2, 4, 8)
 
-def timed_sweep(run_fn, grid_runs):
+
+def _timed_sweep(run_fn, grid_runs: int):
     t0 = time.perf_counter()
     jax.block_until_ready(run_fn().comm_rate)        # compile + first exec
     t1 = time.perf_counter()
@@ -53,7 +44,14 @@ def timed_sweep(run_fn, grid_runs):
                      runs_per_s=grid_runs / (t2 - t1),
                      us_per_call=(t2 - t1) * 1e6 / grid_runs)
 
-if cfg["suite"] == "device_frontier":
+
+def _device_fields(devices: int) -> dict:
+    dev = jax.devices()[0]
+    return dict(devices=devices, platform=dev.platform,
+                device_kind=dev.device_kind)
+
+
+def _frontier_row(devices: int, cfg: dict) -> dict:
     gw = GridWorld()
     prob = gw.vfa_problem(np.zeros(gw.num_states))
     w0 = jnp.zeros(gw.num_states)
@@ -66,14 +64,17 @@ if cfg["suite"] == "device_frontier":
         trace="summary")
     sampler = ParamSampler(fn=gw.sampler_fn(10),
                            params=gw.agent_params(w0, cfg["agents"]))
+    mesh = make_sweep_mesh(devices)
     runs = int(np.prod(spec.grid_shape))
-    _, t = timed_sweep(lambda: run_sweep(spec, sampler, w0, problem=prob,
-                                         mesh=mesh), runs)
+    _, t = _timed_sweep(lambda: run_sweep(spec, sampler, w0, problem=prob,
+                                          mesh=mesh), runs)
     t.update(bench="sweep_scaling", suite="device_frontier",
-             devices=jax.device_count(), iters=cfg["iters"],
-             agents=cfg["agents"])
-    print(json.dumps(t), flush=True)
-else:
+             iters=cfg["iters"], agents=cfg["agents"],
+             **_device_fields(devices))
+    return t
+
+
+def _family_row(cfg: dict) -> dict:
     envs, fam = garnet_env_family(cfg["env_instances"], num_states=20)
     w0 = jnp.zeros(20)
     spec = SweepSpec(
@@ -83,36 +84,21 @@ else:
         trace="summary")
     sampler = ParamSampler(fn=family_sampler_fn(10),
                            params=envs[0].agent_params(w0, cfg["agents"]))
+    mesh = make_sweep_mesh()
     runs = cfg["env_instances"] * int(np.prod(spec.grid_shape))
-    res, t = timed_sweep(lambda: run_sweep(spec, sampler, w0, env_sets=fam,
-                                           mesh=mesh), runs)
+    res, t = _timed_sweep(lambda: run_sweep(spec, sampler, w0, env_sets=fam,
+                                            mesh=mesh), runs)
     jf = np.asarray(res.j_final)
     env_ax = res.axes.index("env_set")
     non_env = tuple(i for i in range(jf.ndim) if i != env_ax)
     t.update(bench="sweep_scaling", suite="env_family",
-             devices=jax.device_count(),
              env_instances=cfg["env_instances"],
              jitted_calls=1, axes=list(res.axes),
              J_final_mean=float(jf.mean()),
              J_final_spread=float(np.std(jf.mean(axis=non_env))),
-             comm_rate_mean=float(np.mean(np.asarray(res.comm_rate))))
-    print(json.dumps(t), flush=True)
-"""
-
-
-def _subprocess(devices: int, cfg: dict) -> dict | None:
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(REPO, "src"),
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
-    r = subprocess.run([sys.executable, "-c", _CODE, json.dumps(cfg)],
-                       capture_output=True, text=True, cwd=REPO, env=env,
-                       timeout=1800)
-    for line in r.stdout.splitlines():
-        if line.startswith("{"):
-            return json.loads(line)
-    return dict(bench="sweep_scaling", suite=cfg["suite"], devices=devices,
-                error=("subprocess failed: " if r.returncode else
-                       "no output: ") + r.stderr[-500:])
+             comm_rate_mean=float(np.mean(np.asarray(res.comm_rate))),
+             **_device_fields(jax.device_count()))
+    return t
 
 
 def run(smoke: bool = False) -> list[dict]:
@@ -123,15 +109,13 @@ def run(smoke: bool = False) -> list[dict]:
         counts, grid = DEVICE_COUNTS, dict(lambdas=4, seeds=4, iters=200,
                                            agents=4)
         family = dict(env_instances=64, seeds=2, iters=150, agents=4)
-    rows = []
     t0 = time.perf_counter()
-    for d in counts:
-        rows.append(_subprocess(d, dict(suite="device_frontier", **grid)))
-    rows.append(_subprocess(counts[-1], dict(suite="env_family", **family)))
-    base = next((r.get("runs_per_s") for r in rows
-                 if r.get("devices") == 1 and "runs_per_s" in r), None)
+    rows = [_frontier_row(d, grid) for d in counts
+            if d <= jax.device_count()]
+    rows.append(_family_row(family))
+    base = rows[0]["runs_per_s"]
     for r in rows:
-        if base and r.get("suite") == "device_frontier" and "runs_per_s" in r:
+        if r["suite"] == "device_frontier":
             r["speedup_vs_1dev"] = r["runs_per_s"] / base
     rows[0]["sweep_wall_s"] = time.perf_counter() - t0
     return rows

@@ -99,14 +99,16 @@ def practical_gain(g: Array, phi_t: Array, eps: float,
                    *, backend: Optional[str] = None) -> Array:
     """Eq. 15 streaming gain, O(T n): -eps ||g||^2 + eps^2 (1/T) sum (phi_t.g)^2.
 
-    ``backend="pallas"`` routes the (T, n) matvec through the tiled VMEM
-    kernel so Algorithm 1's hot spot runs the same code path benchmarked in
-    benchmarks/kernels_bench.py; off-TPU it executes in interpret mode.
+    ``backend="pallas"`` runs the projection through the family kernel as
+    a one-agent fleet, so Algorithm 1's hot spot runs the same kernel the
+    fused and megastep paths use; off-TPU it executes in interpret mode.
     """
     if _resolve(backend) == "pallas":
         # kernels.ops selects interpret mode by platform (compiled on TPU)
         # and accumulates in f32 regardless of input dtype.
-        return _kernel_ops.practical_gain(phi_t, g, eps=eps)
+        gnorm2, sumproj2 = _kernel_ops.gain_family_stats(
+            phi_t[None], g[None])[0]
+        return -eps * gnorm2 + eps**2 * sumproj2 / phi_t.shape[0]
     return _ref.practical_gain_streaming(g, phi_t, eps)
 
 

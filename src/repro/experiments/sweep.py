@@ -44,7 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from repro import compat
 from repro.core import channel as channel_lib
 from repro.core import gain_dispatch
 from repro.core import vfa as vfa_lib
@@ -126,7 +125,6 @@ class SweepSpec:
     tag: Optional[str] = None
 
     def __post_init__(self):
-        from repro.core import gain_dispatch
         for m in self.modes:
             if m not in MODES:
                 raise ValueError(f"unknown mode {m!r}, must be one of {MODES}")
@@ -294,15 +292,15 @@ def _sweep_exec_impl(per_run, w0, shared_params, param_stack, env_stack,
         return block(per_run, w0, shared_params, param_stack, env_stack,
                      env_terms, shared_terms, channel_stack)
     axis = mesh.axis_names[0]
-    # pallas_call has no shard_map replication rule on jax <= 0.4, so the
-    # kernel-backed gain paths must skip the check; the sweep is pure batch
-    # parallelism (no replicated outputs), so the check adds nothing here —
-    # mesh-vs-single parity is asserted directly by tests/test_sweep_sharded.
-    check_vma = (gain_backend or gain_dispatch.default_backend()) != "pallas"
-    sharded = compat.shard_map(
+    # check_vma=False: every output is sharded over the run axis (nothing
+    # replicated), so the varying-axes check has nothing to verify here,
+    # and under it pallas_call would need a vma on each kernel out_shape —
+    # the kernels stay mesh-agnostic instead.  Mesh-vs-single parity is
+    # asserted directly by tests/test_sweep_sharded.
+    sharded = jax.shard_map(
         block, mesh=mesh,
         in_specs=(PartitionSpec(axis),) + (PartitionSpec(),) * 7,
-        out_specs=PartitionSpec(axis), check_vma=check_vma)
+        out_specs=PartitionSpec(axis), check_vma=False)
     return sharded(per_run, w0, shared_params, param_stack, env_stack,
                    env_terms, shared_terms, channel_stack)
 

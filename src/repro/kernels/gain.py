@@ -5,15 +5,6 @@ footnote 2 of the paper promises O(T n) per agent and these kernels deliver
 it without ever materializing ``Phi_hat = (1/T) sum phi phi^T`` (n x n) in
 HBM.  Two entry points:
 
-* ``gain_matvec`` / ``practical_gain`` — the original single-agent (T, n)
-  matvec.  Tiling: grid (T_tiles, n_tiles); each program multiplies a
-  (BT x BN) VMEM tile of the feature matrix against a (BN,) slice of the
-  gradient and accumulates into the (BT,) projection block — n_tiles is the
-  sequential reduction dimension (TPU grids execute in order, so revisiting
-  the same output block accumulates in VMEM).  BT=256, BN=512 keeps the
-  working set ~0.6 MB, far under the ~16 MB VMEM budget, and both are
-  multiples of the (8,128) f32 tile.
-
 * ``gain_family_stats`` — the batched-agent *family* kernel the fused sweep
   step runs (DESIGN.md §3).  The grid tiles ``(m, T, n)`` directly — agents
   are a grid axis, not a vmap around a scalar kernel — and one pass over the
@@ -25,7 +16,9 @@ HBM.  Two entry points:
   n-scale vector statistics accumulate on the first T-tile only, so nothing
   is computed twice.  One ``pallas_call`` replaces the 3 x m per-agent
   dispatches of the reference path — the call-count reduction
-  ``benchmarks/sweep_step.py`` measures.
+  ``benchmarks/sweep_step.py`` measures.  The single-agent eq.-15 gain of
+  the reference step structure is this kernel at m = 1
+  (``repro.core.gain_dispatch.practical_gain``).
 
 * ``megastep`` — the whole-inner-step kernel (DESIGN.md §7,
   ``step_backend="megastep"``).  One ``pallas_call`` executes everything
@@ -41,6 +34,13 @@ HBM.  Two entry points:
   batching the kernel per run.  The gated gradient sum accumulates in a
   run-wide VMEM scratch row as each agent block's gains complete; the last
   agent block of a run writes ``w_next``.
+
+With the default tiles every BlockSpec obeys the TPU tiling rule — the
+last two block dims are multiples of (8, 128) or equal the array's dims —
+at any (m, T, n); an override keeps it as long as agent and T tiles stay
+multiples of 8 and feature tiles multiples of 128 (or cover the whole
+dim).  ``tests/test_tpu_compile.py`` compiles both kernels for a described
+v5e at the sweep's real widths.
 
 Block constants below are *defaults*: every kernel entry point takes
 per-call overrides, and ``REPRO_KERNEL_BLOCKS`` (comma-separated
@@ -62,12 +62,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-BLOCK_T = 256
-BLOCK_N = 512
-
-# Family-kernel agent block: 8 agents per program keeps the feature block at
-# BM*BT*BN*4B = 1 MB of VMEM while cutting the grid (and, off-TPU, the
-# interpreter's per-step overhead) by 8x versus one agent per program.
+# Family-kernel tiles: (BM, BT, BN) = (8, 128, 256) f32 is a 1 MB feature
+# block, double-buffered well inside the 16 MB scoped VMEM of a v5e core,
+# and every dim is a multiple of the (8, 128) f32 tile.  Not yet tuned on
+# the chip (the defaults were first picked against the interpreter).
 BLOCK_M = 8
 FAMILY_BLOCK_T = 128
 FAMILY_BLOCK_N = 256
@@ -75,9 +73,9 @@ FAMILY_BLOCK_N = 256
 # Megastep agent block: larger than the family kernel's because the gated
 # update needs the full (BM, n) gradient rows resident per agent block
 # anyway, and fewer agent blocks directly cut the Phi/grad_J re-streaming
-# term of the roofline model (revisits = (m/BM) * (T/BT)) as well as the
-# interpreter's per-grid-step overhead off-TPU.  BM*BT*BN*4B = 4 MB of
-# VMEM for the feature block — comfortably under the ~16 MB budget.
+# term of the roofline model (revisits = (m/BM) * (T/BT)).  BM*BT*BN*4B =
+# 4 MB of VMEM for the feature block — double-buffered, half the 16 MB
+# scoped VMEM of a v5e core.  Not yet tuned on the chip.
 MEGASTEP_BLOCK_M = 32
 
 # Column order of the (m, 4) stats array gain_family_stats emits.
@@ -92,8 +90,8 @@ _BLOCKS_ENV = "REPRO_KERNEL_BLOCKS"
 
 # every block constant _block() can resolve; an env override naming
 # anything else is a typo that would otherwise silently do nothing
-_KNOWN_BLOCKS = ("block_t", "block_n", "block_m",
-                 "family_block_t", "family_block_n", "megastep_block_m")
+_KNOWN_BLOCKS = ("block_m", "family_block_t", "family_block_n",
+                 "megastep_block_m")
 
 
 def env_blocks() -> dict[str, int]:
@@ -127,55 +125,6 @@ def _block(name: str, override: Optional[int], default: int) -> int:
     if override is not None:
         return override
     return env_blocks().get(name, default)
-
-
-def _matvec_kernel(phi_ref, g_ref, out_ref):
-    ni = pl.program_id(1)
-
-    @pl.when(ni == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    phi = phi_ref[...].astype(jnp.float32)      # (BT, BN)
-    g = g_ref[...].astype(jnp.float32)          # (1, BN)
-    out_ref[...] += phi @ g[0, :, None]         # (BT, 1) accumulate
-
-
-def gain_matvec(phi: Array, g: Array, *, interpret: bool = True,
-                block_t: Optional[int] = None,
-                block_n: Optional[int] = None) -> Array:
-    """proj = phi @ g via the tiled kernel.  phi: (T, n); g: (n,) -> (T,)."""
-    T, n = phi.shape
-    bt = min(_block("block_t", block_t, BLOCK_T), T)
-    bn = min(_block("block_n", block_n, BLOCK_N), n)
-    pad_t = (-T) % bt
-    pad_n = (-n) % bn
-    if pad_t or pad_n:
-        phi = jnp.pad(phi, ((0, pad_t), (0, pad_n)))
-        g = jnp.pad(g, (0, pad_n))
-    Tp, np_ = phi.shape
-    grid = (Tp // bt, np_ // bn)
-    out = pl.pallas_call(
-        _matvec_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bt, bn), lambda ti, ni: (ti, ni)),
-            pl.BlockSpec((1, bn), lambda ti, ni: (0, ni)),
-        ],
-        out_specs=pl.BlockSpec((bt, 1), lambda ti, ni: (ti, 0)),
-        out_shape=jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
-        interpret=interpret,
-    )(phi, g[None, :])
-    return out[:T, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def practical_gain(phi: Array, g: Array, eps: float = 1.0,
-                   interpret: bool = True) -> Array:
-    """Full eq.-15 gain: -eps ||g||^2 + eps^2 (1/T) sum_t (phi_t . g)^2."""
-    proj = gain_matvec(phi, g, interpret=interpret)
-    gf = g.astype(jnp.float32)
-    return -eps * (gf @ gf) + eps**2 * jnp.sum(proj**2) / phi.shape[0]
 
 
 # ---------------------------------------------------------------------------
@@ -310,7 +259,7 @@ def gain_family_stats(phi: Array, g: Array,
 # ---------------------------------------------------------------------------
 
 
-def _megastep_kernel(with_model: bool, with_deliver: bool, pm_batched: bool,
+def _megastep_kernel(with_model: bool, with_deliver: bool,
                      eps: float, num_samples: int, num_agents: int,
                      block_m: int, *refs):
     """Kernel body: one whole gated-SGD step, grid (R, m-blk, T-tile, n-tile).
@@ -323,7 +272,10 @@ def _megastep_kernel(with_model: bool, with_deliver: bool, pm_batched: bool,
     gains are written, and the gated gradient sum accumulates into a
     run-wide scratch row; the last agent block of each run writes
     ``w_next = w - eps * upd / max(cnt, 1)`` (eq. 6).  Per-run control
-    scalars ride in as a (R, 2) ``[threshold, mode_id]`` array.
+    scalars ``[threshold, mode_id]`` sit in SMEM as one flat (2R,) array.
+    Per-agent values travel as (BM, 1) columns — the layout the lane
+    reductions produce — so their blocks satisfy the TPU (8, 128) rule for
+    any agent block that is a multiple of 8.
 
     ``with_deliver`` adds the lossy-channel keep mask (repro.core.channel):
     the gated-update accumulation aggregates ``alphas * deliver`` — one
@@ -331,7 +283,7 @@ def _megastep_kernel(with_model: bool, with_deliver: bool, pm_batched: bool,
     stays the attempted transmissions.
     """
     refs = list(refs)
-    (phi_ref, gcol_ref, gfull_ref, ctl_ref, arand_ref, w_ref) = refs[:6]
+    (ctl_ref, phi_ref, gcol_ref, gfull_ref, arand_ref, w_ref) = refs[:6]
     refs = refs[6:]
     dlv_ref = refs.pop(0) if with_deliver else None
     if with_model:
@@ -339,6 +291,7 @@ def _megastep_kernel(with_model: bool, with_deliver: bool, pm_batched: bool,
         refs = refs[2:]
     (wout_ref, aout_ref, gout_ref,
      proj_ref, stats_ref, upd_ref, cnt_ref) = refs
+    r = pl.program_id(0)
     ai = pl.program_id(1)
     ti = pl.program_id(2)
     ni = pl.program_id(3)
@@ -361,8 +314,8 @@ def _megastep_kernel(with_model: bool, with_deliver: bool, pm_batched: bool,
     def _init_proj():
         proj_ref[...] = jnp.zeros_like(proj_ref)
 
-    phi = phi_ref[0].astype(jnp.float32)            # (BM, BT, BN)
-    g = gcol_ref[0].astype(jnp.float32)             # (BM, BN)
+    phi = phi_ref[...].astype(jnp.float32)          # (BM, BT, BN)
+    g = gcol_ref[...].astype(jnp.float32)           # (BM, BN)
     proj_ref[...] += jax.lax.dot_general(
         phi, g, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)         # (BM, BT)
@@ -372,9 +325,8 @@ def _megastep_kernel(with_model: bool, with_deliver: bool, pm_batched: bool,
         stats_ref[:, STAT_GNORM2] += jnp.sum(g * g, axis=-1)
         if with_model:
             gj = gj_ref[0].astype(jnp.float32)                  # (BN,)
-            pm = (pm_ref[0] if pm_batched else
-                  pm_ref[...]).astype(jnp.float32)              # (BN, n_pad)
-            gfull = gfull_ref[0].astype(jnp.float32)            # (BM, n_pad)
+            pm = pm_ref[...].astype(jnp.float32)                # (BN, n_pad)
+            gfull = gfull_ref[...].astype(jnp.float32)          # (BM, n_pad)
             stats_ref[:, STAT_GDOTJ] += g @ gj
             stats_ref[:, STAT_QUAD] += jnp.sum(
                 jnp.dot(g, pm, preferred_element_type=jnp.float32) * gfull,
@@ -388,41 +340,45 @@ def _megastep_kernel(with_model: bool, with_deliver: bool, pm_batched: bool,
     @pl.when(last)
     def _gate_and_update():
         s = stats_ref[...]
-        prac = -eps * s[:, STAT_GNORM2] + eps**2 * s[:, STAT_SUMPROJ2] / num_samples
-        norm = -eps * s[:, STAT_GNORM2]
+
+        def col(c):                                             # (BM, 1)
+            return s[:, c:c + 1]
+
+        prac = (-eps * col(STAT_GNORM2)
+                + eps**2 * col(STAT_SUMPROJ2) / num_samples)
+        norm = -eps * col(STAT_GNORM2)
         if with_model:
-            theo = -eps * s[:, STAT_GDOTJ] + eps**2 * s[:, STAT_QUAD]
+            theo = -eps * col(STAT_GDOTJ) + eps**2 * col(STAT_QUAD)
         else:
             theo = prac   # spec validation keeps mode != theoretical
-        thresh = ctl_ref[0, 0]
-        mode = ctl_ref[0, 1]
+        thresh = ctl_ref[2 * r]
+        mode = ctl_ref[2 * r + 1]
         gains = jnp.where(mode == _MODE_THEORETICAL, theo,
                           jnp.where(mode == _MODE_NORM, norm, prac))
         gate = (gains <= -thresh).astype(jnp.float32)
         alphas = jnp.where(mode == _MODE_ALWAYS, 1.0,
                            jnp.where(mode == _MODE_NEVER, 0.0,
                                      jnp.where(mode == _MODE_RANDOM,
-                                               arand_ref[0], gate)))
+                                               arand_ref[...], gate)))
         # zero padded agents so they never transmit (the gated mean divides
         # by the transmitter count — a phantom always-mode agent would skew
-        # it); 2D iota then squeeze keeps the op TPU-legal
+        # it)
         idx = ai * block_m + jax.lax.broadcasted_iota(
-            jnp.int32, (block_m, 1), 0)[:, 0]
+            jnp.int32, (block_m, 1), 0)
         alphas = alphas * (idx < num_agents).astype(jnp.float32)
-        gout_ref[...] = gains[None]
-        aout_ref[...] = alphas[None]
+        gout_ref[...] = gains
+        aout_ref[...] = alphas
         # channel keep mask: only delivered transmissions enter the update
-        eff = alphas * dlv_ref[0] if with_deliver else alphas
-        gfull = gfull_ref[0].astype(jnp.float32)                # (BM, n_pad)
-        upd_ref[...] += jnp.dot(eff[None, :], gfull,
-                                preferred_element_type=jnp.float32)
-        cnt_ref[...] += jnp.sum(eff)[None, None]
+        eff = alphas * dlv_ref[...] if with_deliver else alphas
+        gfull = gfull_ref[...].astype(jnp.float32)              # (BM, n_pad)
+        upd_ref[...] += jnp.sum(eff * gfull, axis=0, keepdims=True)
+        cnt_ref[...] += jnp.sum(eff, keepdims=True)
 
     @pl.when(jnp.logical_and(ai == na - 1, last))
     def _write_weights():
-        w = w_ref[0].astype(jnp.float32)                        # (n_pad,)
-        upd = upd_ref[0] / jnp.maximum(cnt_ref[0, 0], 1.0)
-        wout_ref[...] = (w - eps * upd)[None]
+        w = w_ref[...].astype(jnp.float32)                      # (1, n_pad)
+        upd = upd_ref[...] / jnp.maximum(cnt_ref[...], 1.0)
+        wout_ref[...] = w - eps * upd
 
 
 def megastep_call(phi: Array, g: Array, w: Array, ctl: Array,
@@ -441,7 +397,9 @@ def megastep_call(phi: Array, g: Array, w: Array, ctl: Array,
       phi:        (R, m, T, n) per-agent local feature batches.
       g:          (R, m, n) per-agent stochastic gradients.
       w:          (R, n) current server weights.
-      ctl:        (R, 2) f32 per-run control ``[threshold, mode_id]``.
+      ctl:        (R, 2) f32 per-run control ``[threshold, mode_id]``; it
+                  sits whole in the 1 MiB SMEM of a v5e core, which bounds
+                  one call to about 100k runs.
       alpha_rand: (R, m) pre-drawn f32 bernoulli decisions (random mode).
       grad_j:     (R, n) exact grad J(w), or None when no model is given.
       phi_matrix: (n, n) grid-shared — or (R, n, n) per-run — exact second
@@ -482,43 +440,44 @@ def megastep_call(phi: Array, g: Array, w: Array, ctl: Array,
                 + ((0, pad_n), (0, pad_n)))
     _, mp, Tp, np_ = phi.shape
     grid = (R, mp // bm, Tp // bt, np_ // bn)
+    # Run dims are squeezed (None): every block's last two dims are then
+    # agent/feature tiles or whole array dims, never a 1 against R.
+    agent_col = pl.BlockSpec((None, bm, 1), lambda r, a, t, i: (r, a, 0))
+    run_row = pl.BlockSpec((None, 1, np_), lambda r, a, t, i: (r, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, bm, bt, bn), lambda r, a, t, i: (r, a, t, i)),
-        pl.BlockSpec((1, bm, bn), lambda r, a, t, i: (r, a, i)),
-        pl.BlockSpec((1, bm, np_), lambda r, a, t, i: (r, a, 0)),
-        pl.BlockSpec((1, 2), lambda r, a, t, i: (r, 0)),
-        pl.BlockSpec((1, bm), lambda r, a, t, i: (r, a)),
-        pl.BlockSpec((1, np_), lambda r, a, t, i: (r, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        pl.BlockSpec((None, bm, bt, bn), lambda r, a, t, i: (r, a, t, i)),
+        pl.BlockSpec((None, bm, bn), lambda r, a, t, i: (r, a, i)),
+        pl.BlockSpec((None, bm, np_), lambda r, a, t, i: (r, a, 0)),
+        agent_col,
+        run_row,
     ]
-    operands = [phi, g, g, ctl, alpha_rand, w]
+    operands = [ctl.astype(jnp.float32).reshape(2 * R), phi, g, g,
+                alpha_rand[..., None], w[:, None, :]]
     with_deliver = deliver is not None
     if with_deliver:
-        in_specs.append(pl.BlockSpec((1, bm), lambda r, a, t, i: (r, a)))
-        operands.append(deliver)
-    pm_batched = with_model and phi_matrix.ndim == 3
+        in_specs.append(agent_col)
+        operands.append(deliver[..., None])
     if with_model:
-        in_specs.append(pl.BlockSpec((1, bn), lambda r, a, t, i: (r, i)))
-        if pm_batched:
+        in_specs.append(
+            pl.BlockSpec((None, 1, bn), lambda r, a, t, i: (r, 0, i)))
+        if phi_matrix.ndim == 3:
             in_specs.append(
-                pl.BlockSpec((1, bn, np_), lambda r, a, t, i: (r, i, 0)))
+                pl.BlockSpec((None, bn, np_), lambda r, a, t, i: (r, i, 0)))
         else:
             in_specs.append(
                 pl.BlockSpec((bn, np_), lambda r, a, t, i: (i, 0)))
-        operands += [grad_j, phi_matrix]
+        operands += [grad_j[:, None, :], phi_matrix]
     w_next, alphas, gains = pl.pallas_call(
         functools.partial(_megastep_kernel, with_model, with_deliver,
-                          pm_batched, eps, T, m, bm),
+                          eps, T, m, bm),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, np_), lambda r, a, t, i: (r, 0)),
-            pl.BlockSpec((1, bm), lambda r, a, t, i: (r, a)),
-            pl.BlockSpec((1, bm), lambda r, a, t, i: (r, a)),
-        ],
+        out_specs=[run_row, agent_col, agent_col],
         out_shape=[
-            jax.ShapeDtypeStruct((R, np_), jnp.float32),
-            jax.ShapeDtypeStruct((R, mp), jnp.float32),
-            jax.ShapeDtypeStruct((R, mp), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1, np_), jnp.float32),
+            jax.ShapeDtypeStruct((R, mp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((R, mp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, bt), jnp.float32),    # projection accumulator
@@ -528,7 +487,7 @@ def megastep_call(phi: Array, g: Array, w: Array, ctl: Array,
         ],
         interpret=interpret,
     )(*operands)
-    return w_next[:, :n], alphas[:, :m], gains[:, :m]
+    return w_next[:, 0, :n], alphas[:, :m, 0], gains[:, :m, 0]
 
 
 @functools.lru_cache(maxsize=None)

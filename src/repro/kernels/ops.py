@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU —
-the kernels are written for TPU BlockSpec tiling and validated here through
-the interpreter against the pure-jnp oracles in ``repro.kernels.ref``.
+The interpret flag is keyed on the platform: on a TPU every kernel is
+compiled by Mosaic, anywhere else it runs through the Pallas interpreter
+(how the CPU test suite checks it against the pure-jnp oracles in
+``repro.kernels.ref``).  No wrapper falls back to another implementation.
 """
 
 from __future__ import annotations
@@ -13,9 +14,7 @@ import jax
 
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.gain import gain_family_stats as _gain_family_stats
-from repro.kernels.gain import gain_matvec as _gain_matvec
 from repro.kernels.gain import megastep as _megastep
-from repro.kernels.gain import practical_gain as _practical_gain
 from repro.kernels.ssd_scan import ssd_chunked_pallas as _ssd
 
 Array = jax.Array
@@ -30,16 +29,6 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                     window: int = 0, block_q: int = 128, block_k: int = 512) -> Array:
     return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
                   block_k=block_k, interpret=_default_interpret())
-
-
-@jax.jit
-def gain_matvec(phi: Array, g: Array) -> Array:
-    return _gain_matvec(phi, g, interpret=_default_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("eps",))
-def practical_gain(phi: Array, g: Array, eps: float = 1.0) -> Array:
-    return _practical_gain(phi, g, eps=eps, interpret=_default_interpret())
 
 
 @jax.jit

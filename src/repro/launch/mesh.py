@@ -10,18 +10,10 @@ from __future__ import annotations
 
 import jax
 
-# jax.sharding.AxisType landed after 0.4.x; all our axes are Auto (the
-# default collective-matters semantics), so on older jax we simply omit the
-# kwarg — jax.make_mesh there has no axis_types parameter and every axis is
-# implicitly Auto.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _make_mesh(shape, axes):
-    if _AXIS_TYPE is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(_AXIS_TYPE.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_serve_step
 from repro.configs.base import ShapeConfig
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--greedy", action="store_true", default=True)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

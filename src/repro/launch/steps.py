@@ -168,8 +168,7 @@ def build_train_step(
         return jax.tree.map(keep, spec_tree,
                             is_leaf=lambda x: isinstance(x, P) or x is None)
 
-    from repro.compat import shard_map
-    smapped = shard_map(
+    smapped = jax.shard_map(
         core,
         mesh=mesh,
         in_specs=(

@@ -24,6 +24,7 @@ from repro.checkpoint import save as save_ckpt
 from repro.configs import ARCH_NAMES, get_config
 from repro.core.fed_sgd import FedConfig, FedStats
 from repro.data.synthetic_lm import SyntheticLMConfig, make_lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import federation_axis, make_host_mesh, make_production_mesh
 from repro.launch.steps import build_train_step
 from repro.models import build_model
@@ -76,6 +77,7 @@ def main() -> None:
     ap.add_argument("--checkpoint", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -65,8 +65,8 @@ def _sampler():
 
 def test_env_blocks_parses_known_names(monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_BLOCKS",
-                       "block_t=64, megastep_block_m=8")
-    assert env_blocks() == {"block_t": 64, "megastep_block_m": 8}
+                       "family_block_t=64, megastep_block_m=8")
+    assert env_blocks() == {"family_block_t": 64, "megastep_block_m": 8}
 
 
 def test_env_blocks_rejects_unknown_name(monkeypatch):
@@ -80,10 +80,10 @@ def test_env_blocks_rejects_unknown_name(monkeypatch):
 
 
 def test_env_blocks_bad_int_names_the_env_var(monkeypatch):
-    monkeypatch.setenv("REPRO_KERNEL_BLOCKS", "block_t=sixty-four")
+    monkeypatch.setenv("REPRO_KERNEL_BLOCKS", "family_block_t=sixty-four")
     with pytest.raises(ValueError, match="REPRO_KERNEL_BLOCKS") as e:
         env_blocks()
-    assert "block_t" in str(e.value)
+    assert "family_block_t" in str(e.value)
     assert "sixty-four" in str(e.value)
 
 
