@@ -19,13 +19,6 @@ Stages, swept over (m, T, n):
   kernel (agent block MEGASTEP_BLOCK_M=32 vs the family kernel's 8, so it
   also runs a quarter of the grid programs), closing the Amdahl gap the
   fused rows leave open.
-* ``attribution`` — per-stage cost split of the reference step:
-  ``sample_grad`` (sampling + per-agent gradients, measured by a scan that
-  stops there), ``gain_family`` (measured per call), and ``post_gain``
-  (trigger + gated update, DERIVED as full - sample_grad - gain_family and
-  clamped at 0 — it is the HBM-round-trip slice megastep eliminates).
-  Derived rows carry ``derived=true`` and inherit the noise of all three
-  measurements.
 * ``sweep_step`` — R runs vmapped through the full step (the sweep
   engine's hot loop), reported per run-step.  On the pallas path the
   megastep rows ride the kernel's native run-grid axis (custom_vmap):
@@ -138,29 +131,6 @@ def _bench_full_step(m, T, n, gain_backend, step_backend, num_iterations,
     return us_total / num_iterations
 
 
-def _bench_sample_grad(m, T, n, num_iterations, reps, trials):
-    """The step's pre-gain slice: sampling + per-agent gradients only."""
-    from repro.core import vfa as vfa_lib
-    rng = np.random.default_rng(1)
-    w_true = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
-    sample_all = _make_sample_all(T, n, w_true)
-
-    def run(key):
-        def step(w, rng_k):
-            rngs = jax.random.split(rng_k, m + 1)
-            phi_b, targets_b = sample_all(rngs[:-1])
-            grads = jax.vmap(vfa_lib.stochastic_gradient,
-                             in_axes=(None, 0, 0))(w, phi_b, targets_b)
-            return w - 1e-6 * jnp.sum(grads, axis=0), None
-        w, _ = jax.lax.scan(step, jnp.zeros((n,)),
-                            jax.random.split(key, num_iterations))
-        return w
-
-    fn = jax.jit(run)
-    us_total = _median_time(fn, jax.random.key(0), reps=reps, trials=trials)
-    return us_total / num_iterations
-
-
 def _bench_sweep_step(m, T, n, gain_backend, step_backend, num_iterations,
                       runs, reps, trials):
     """R runs vmapped through the full step, us per (run, step).
@@ -210,22 +180,6 @@ def run(smoke: bool = False) -> list[dict]:
                     gain_backend=backend, step_backend=sb,
                     us_per_call=times[sb],
                     speedup_vs_reference=times["reference"] / times[sb]))
-            # per-stage attribution of the reference step: what megastep
-            # can and cannot touch (sample_grad is outside the fusion
-            # boundary — the Amdahl floor)
-            sample = _bench_sample_grad(m, T, n, num_iterations,
-                                        step_reps, trials)
-            gain = _bench_gain_family(m, T, n, backend, "reference",
-                                      reps, trials)
-            post = max(times["reference"] - sample - gain, 0.0)
-            for comp, us, derived in (("sample_grad", sample, False),
-                                      ("gain_family", gain, False),
-                                      ("post_gain", post, True)):
-                rows.append(dict(
-                    bench="sweep_step", stage="attribution", m=m, T=T, n=n,
-                    gain_backend=backend, component=comp, us_per_call=us,
-                    fraction_of_step=us / times["reference"],
-                    derived=derived))
 
     for backend in ("reference", "pallas"):
         for (m, T, n) in sweep_shapes:

@@ -334,50 +334,65 @@ def gated_sgd_core(
         Shared verbatim by the full and summary scans so both trace
         policies execute identical per-step arithmetic.  ``st`` is the
         sampler-state pytree (``None`` — an empty carry — on the
-        stateless/i.i.d. path).
+        stateless/i.i.d. path).  Each stage runs under a named scope
+        (``keys``, ``sample``, ``grad``, ``gains``, ``trigger``,
+        ``update``), which names its ops in the compiled program and the
+        device trace and changes no op.
         """
-        rngs = jax.random.split(rng_k, num_agents + 1)
-        if stateful:
-            st, phi_b, targets_b = sample_all(st, w, rngs[:-1])
-        else:
-            phi_b, targets_b = sample_all(rngs[:-1])
-        grads = jax.vmap(vfa_lib.stochastic_gradient, in_axes=(None, 0, 0))(
-            w, phi_b, targets_b)
-        grad_j = terms.grad(w) if terms is not None else None
+        with jax.named_scope("keys"):
+            rngs = jax.random.split(rng_k, num_agents + 1)
+        with jax.named_scope("sample"):
+            if stateful:
+                st, phi_b, targets_b = sample_all(st, w, rngs[:-1])
+            else:
+                phi_b, targets_b = sample_all(rngs[:-1])
+        with jax.named_scope("grad"):
+            grads = jax.vmap(vfa_lib.stochastic_gradient,
+                             in_axes=(None, 0, 0))(w, phi_b, targets_b)
+        with jax.named_scope("gains"):
+            grad_j = terms.grad(w) if terms is not None else None
         if step_backend_r == "megastep":
             # the whole rest of the step — gains, trigger, gated update —
             # is one dispatch; rngs[-1] feeds the same bernoulli draw as
             # the reference path so RNG streams match bitwise
+            with jax.named_scope("trigger"):
+                alpha_rand = jax.random.bernoulli(
+                    rngs[-1], tx_prob, (num_agents,)).astype(jnp.float32)
+            with jax.named_scope("gains"):
+                w_next, alphas, gains = gain_dispatch.megastep(
+                    mode_id, w, grads, phi_b, eps, thresholds[k],
+                    alpha_rand, grad_j, phi_matrix, backend=gain_backend)
+            return w_next, st, alphas, gains
+        with jax.named_scope("gains"):
+            gains = gain_dispatch.mode_gains(
+                mode_id, grads, phi_b, eps, grad_j, phi_matrix,
+                backend=gain_backend, step_backend=step_backend)
+        with jax.named_scope("trigger"):
+            alpha_gate = should_transmit(gains, thresholds[k])
             alpha_rand = jax.random.bernoulli(
                 rngs[-1], tx_prob, (num_agents,)).astype(jnp.float32)
-            w_next, alphas, gains = gain_dispatch.megastep(
-                mode_id, w, grads, phi_b, eps, thresholds[k], alpha_rand,
-                grad_j, phi_matrix, backend=gain_backend)
-            return w_next, st, alphas, gains
-        gains = gain_dispatch.mode_gains(
-            mode_id, grads, phi_b, eps, grad_j, phi_matrix,
-            backend=gain_backend, step_backend=step_backend)
-        alpha_gate = should_transmit(gains, thresholds[k])
-        alpha_rand = jax.random.bernoulli(
-            rngs[-1], tx_prob, (num_agents,)).astype(jnp.float32)
-        alphas = jnp.where(
-            mode_id == gain_dispatch.MODE_ALWAYS, jnp.ones(num_agents),
-            jnp.where(mode_id == gain_dispatch.MODE_NEVER, jnp.zeros(num_agents),
-                      jnp.where(mode_id == gain_dispatch.MODE_RANDOM,
-                                alpha_rand, alpha_gate)))
-        # Barrier so XLA cannot constant-fold alphas when mode_id is static
-        # (always-mode all-ones would otherwise fuse differently than the
-        # traced-mode program, breaking per-run <-> sweep bit-compatibility).
-        # Only needed — and only legal, the primitive has no batching rule —
-        # when mode_id is concrete; traced mode_id keeps alphas runtime.
-        if not isinstance(mode_id, jax.core.Tracer):
-            alphas = jax.lax.optimization_barrier(alphas)
-        w_next = server_lib.server_update(w, grads, alphas, eps)
+            alphas = jnp.where(
+                mode_id == gain_dispatch.MODE_ALWAYS, jnp.ones(num_agents),
+                jnp.where(mode_id == gain_dispatch.MODE_NEVER,
+                          jnp.zeros(num_agents),
+                          jnp.where(mode_id == gain_dispatch.MODE_RANDOM,
+                                    alpha_rand, alpha_gate)))
+            # Barrier so XLA cannot constant-fold alphas when mode_id is
+            # static (always-mode all-ones would otherwise fuse differently
+            # than the traced-mode program, breaking per-run <-> sweep
+            # bit-compatibility).  Only needed — and only legal, the
+            # primitive has no batching rule — when mode_id is concrete;
+            # traced mode_id keeps alphas runtime.
+            if not isinstance(mode_id, jax.core.Tracer):
+                alphas = jax.lax.optimization_barrier(alphas)
+        with jax.named_scope("update"):
+            w_next = server_lib.server_update(w, grads, alphas, eps)
         return w_next, st, alphas, gains
 
     rngs = jax.random.split(rng, N)
 
     if trace == "full":
+        @jax.named_scope("step")
         def step(carry, inp):
             w, st = carry
             k, rng_k = inp
@@ -392,19 +407,21 @@ def gated_sgd_core(
         return InnerTrace(weights=weights, alphas=alphas, gains=gains,
                           comm_rate=comm_rate)
 
+    @jax.named_scope("step")
     def step_summary(carry, inp):
         w, st, tx_counts, gain_sum, gain_min, gain_max = carry
         k, rng_k = inp
         w_next, st, alphas, gains = step_body(w, st, k, rng_k)
-        carry = (w_next, st,
-                 tx_counts + alphas,
-                 gain_sum + gains,
-                 jnp.minimum(gain_min, gains),
-                 jnp.maximum(gain_max, gains))
-        ys = (terms.objective(w_next)
-              if trace.j_trajectory and terms is not None else None,
-              alphas if trace.alphas else None,
-              gains if trace.gains else None)
+        with jax.named_scope("summary"):
+            carry = (w_next, st,
+                     tx_counts + alphas,
+                     gain_sum + gains,
+                     jnp.minimum(gain_min, gains),
+                     jnp.maximum(gain_max, gains))
+            ys = (terms.objective(w_next)
+                  if trace.j_trajectory and terms is not None else None,
+                  alphas if trace.alphas else None,
+                  gains if trace.gains else None)
         return carry, ys
 
     m = num_agents
@@ -475,58 +492,76 @@ def _channel_core(
     stateful = sampler_state is not None
 
     def step_body(w, st, stale_buf, pend_sum, pend_cnt, k, rng_k):
-        rngs = jax.random.split(rng_k, num_agents + 1)
-        keep = jax.random.bernoulli(
-            jax.random.fold_in(rng_k, 1), 1.0 - channel.drop_prob,
-            (num_agents,)).astype(jnp.float32)
-        w_stale = jnp.take(stale_buf, (k - channel.staleness) % stale_cap,
-                           axis=0)
-        if stateful:
-            # the stateful sampler sees what the *agent* sees: the s-step-
-            # stale weights drive the TD bootstrap, matching the gains/grads
-            st, phi_b, targets_b = sample_all(st, w_stale, rngs[:-1])
-        else:
-            phi_b, targets_b = sample_all(rngs[:-1])
-        grads = jax.vmap(vfa_lib.stochastic_gradient, in_axes=(None, 0, 0))(
-            w_stale, phi_b, targets_b)
-        grad_j = terms.grad(w_stale) if terms is not None else None
+        # named scopes as in gated_sgd_core's step, plus ``channel``: the
+        # drop draw, the stale-weights ring and the delay ring
+        with jax.named_scope("keys"):
+            rngs = jax.random.split(rng_k, num_agents + 1)
+        with jax.named_scope("channel"):
+            keep = jax.random.bernoulli(
+                jax.random.fold_in(rng_k, 1), 1.0 - channel.drop_prob,
+                (num_agents,)).astype(jnp.float32)
+            w_stale = jnp.take(stale_buf,
+                               (k - channel.staleness) % stale_cap, axis=0)
+        with jax.named_scope("sample"):
+            if stateful:
+                # the stateful sampler sees what the *agent* sees: the s-
+                # step-stale weights drive the TD bootstrap, matching the
+                # gains/grads
+                st, phi_b, targets_b = sample_all(st, w_stale, rngs[:-1])
+            else:
+                phi_b, targets_b = sample_all(rngs[:-1])
+        with jax.named_scope("grad"):
+            grads = jax.vmap(vfa_lib.stochastic_gradient,
+                             in_axes=(None, 0, 0))(w_stale, phi_b, targets_b)
+        with jax.named_scope("gains"):
+            grad_j = terms.grad(w_stale) if terms is not None else None
         if step_backend_r == "megastep":
             # delay_cap == 1 here (checked at dispatch): the kernel's fused
             # update IS the immediate arrival; the deliver mask rides into
             # the kernel as one extra multiply after the threshold compare
-            alpha_rand = jax.random.bernoulli(
-                rngs[-1], tx_prob, (num_agents,)).astype(jnp.float32)
-            w_next, alphas, gains = gain_dispatch.megastep(
-                mode_id, w, grads, phi_b, eps, thresholds[k], alpha_rand,
-                grad_j, phi_matrix, backend=gain_backend, deliver=keep)
-            delivered = alphas * keep
+            with jax.named_scope("trigger"):
+                alpha_rand = jax.random.bernoulli(
+                    rngs[-1], tx_prob, (num_agents,)).astype(jnp.float32)
+            with jax.named_scope("gains"):
+                w_next, alphas, gains = gain_dispatch.megastep(
+                    mode_id, w, grads, phi_b, eps, thresholds[k],
+                    alpha_rand, grad_j, phi_matrix, backend=gain_backend,
+                    deliver=keep)
+            with jax.named_scope("channel"):
+                delivered = alphas * keep
         else:
-            gains = gain_dispatch.mode_gains(
-                mode_id, grads, phi_b, eps, grad_j, phi_matrix,
-                backend=gain_backend, step_backend=step_backend)
-            alpha_gate = should_transmit(gains, thresholds[k])
-            alpha_rand = jax.random.bernoulli(
-                rngs[-1], tx_prob, (num_agents,)).astype(jnp.float32)
-            alphas = jnp.where(
-                mode_id == gain_dispatch.MODE_ALWAYS, jnp.ones(num_agents),
-                jnp.where(mode_id == gain_dispatch.MODE_NEVER,
-                          jnp.zeros(num_agents),
-                          jnp.where(mode_id == gain_dispatch.MODE_RANDOM,
-                                    alpha_rand, alpha_gate)))
-            if not isinstance(mode_id, jax.core.Tracer):
-                alphas = jax.lax.optimization_barrier(alphas)
-            delivered = alphas * keep
-            pend_sum = jax.lax.dynamic_update_index_in_dim(
-                pend_sum, jnp.einsum("m,mn->n", delivered, grads),
-                k % delay_cap, 0)
-            pend_cnt = jax.lax.dynamic_update_index_in_dim(
-                pend_cnt, jnp.sum(delivered), k % delay_cap, 0)
-            slot = (k - channel.delay) % delay_cap
-            arrived = jnp.take(pend_sum, slot, axis=0)
-            arrived_cnt = jnp.take(pend_cnt, slot, axis=0)
-            w_next = w - eps * (arrived / jnp.maximum(arrived_cnt, 1.0))
-        stale_buf = jax.lax.dynamic_update_index_in_dim(
-            stale_buf, w_next, (k + 1) % stale_cap, 0)
+            with jax.named_scope("gains"):
+                gains = gain_dispatch.mode_gains(
+                    mode_id, grads, phi_b, eps, grad_j, phi_matrix,
+                    backend=gain_backend, step_backend=step_backend)
+            with jax.named_scope("trigger"):
+                alpha_gate = should_transmit(gains, thresholds[k])
+                alpha_rand = jax.random.bernoulli(
+                    rngs[-1], tx_prob, (num_agents,)).astype(jnp.float32)
+                alphas = jnp.where(
+                    mode_id == gain_dispatch.MODE_ALWAYS,
+                    jnp.ones(num_agents),
+                    jnp.where(mode_id == gain_dispatch.MODE_NEVER,
+                              jnp.zeros(num_agents),
+                              jnp.where(mode_id == gain_dispatch.MODE_RANDOM,
+                                        alpha_rand, alpha_gate)))
+                if not isinstance(mode_id, jax.core.Tracer):
+                    alphas = jax.lax.optimization_barrier(alphas)
+            with jax.named_scope("channel"):
+                delivered = alphas * keep
+                pend_sum = jax.lax.dynamic_update_index_in_dim(
+                    pend_sum, jnp.einsum("m,mn->n", delivered, grads),
+                    k % delay_cap, 0)
+                pend_cnt = jax.lax.dynamic_update_index_in_dim(
+                    pend_cnt, jnp.sum(delivered), k % delay_cap, 0)
+                slot = (k - channel.delay) % delay_cap
+                arrived = jnp.take(pend_sum, slot, axis=0)
+                arrived_cnt = jnp.take(pend_cnt, slot, axis=0)
+            with jax.named_scope("update"):
+                w_next = w - eps * (arrived / jnp.maximum(arrived_cnt, 1.0))
+        with jax.named_scope("channel"):
+            stale_buf = jax.lax.dynamic_update_index_in_dim(
+                stale_buf, w_next, (k + 1) % stale_cap, 0)
         return (w_next, st, stale_buf, pend_sum, pend_cnt,
                 alphas, gains, delivered)
 
@@ -536,6 +571,7 @@ def _channel_core(
                   jnp.zeros((delay_cap,)))
 
     if trace == "full":
+        @jax.named_scope("step")
         def step(carry, inp):
             k, rng_k = inp
             (w_next, st, stale_buf, ps, pc,
@@ -550,6 +586,7 @@ def _channel_core(
         return InnerTrace(weights=weights, alphas=alphas, gains=gains,
                           comm_rate=jnp.mean(alphas), delivered=delivered)
 
+    @jax.named_scope("step")
     def step_summary(carry, inp):
         (w, st, stale_buf, ps, pc, tx_counts, dl_counts,
          gain_sum, gain_min, gain_max) = carry
@@ -557,16 +594,17 @@ def _channel_core(
         (w_next, st, stale_buf, ps, pc,
          alphas, gains, delivered) = step_body(w, st, stale_buf, ps, pc,
                                                k, rng_k)
-        carry = (w_next, st, stale_buf, ps, pc,
-                 tx_counts + alphas,
-                 dl_counts + delivered,
-                 gain_sum + gains,
-                 jnp.minimum(gain_min, gains),
-                 jnp.maximum(gain_max, gains))
-        ys = (terms.objective(w_next)
-              if trace.j_trajectory and terms is not None else None,
-              alphas if trace.alphas else None,
-              gains if trace.gains else None)
+        with jax.named_scope("summary"):
+            carry = (w_next, st, stale_buf, ps, pc,
+                     tx_counts + alphas,
+                     dl_counts + delivered,
+                     gain_sum + gains,
+                     jnp.minimum(gain_min, gains),
+                     jnp.maximum(gain_max, gains))
+            ys = (terms.objective(w_next)
+                  if trace.j_trajectory and terms is not None else None,
+                  alphas if trace.alphas else None,
+                  gains if trace.gains else None)
         return carry, ys
 
     init = (w0, sampler_state) + init_rings + (

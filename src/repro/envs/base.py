@@ -109,17 +109,25 @@ def family_sampler_fn(num_samples: int):
     """
 
     def fn(env_params, params, rng):
+        # each stage under a named scope, which names its ops in the
+        # compiled program and the device trace
         P, c = env_params["P"], env_params["c"]          # (S, A, S), (S,)
         S, A = P.shape[0], P.shape[1]
         r_x, r_a, r_n, r_t = jax.random.split(rng, 4)
-        x = jax.random.categorical(r_x, params["visit_logits"],
-                                   shape=(num_samples,))
-        a = jax.random.randint(r_a, (num_samples,), 0, A)
-        x_next = jax.random.categorical(r_n, jnp.log(P[x, a] + 1e-30), axis=-1)
-        targets = (c[x] + env_params["gamma"] * params["v"][x_next]
-                   + params["noise_scale"]
-                   * jax.random.normal(r_t, (num_samples,)))
-        return jax.nn.one_hot(x, S), targets
+        with jax.named_scope("state_draw"):
+            x = jax.random.categorical(r_x, params["visit_logits"],
+                                       shape=(num_samples,))
+        with jax.named_scope("action_draw"):
+            a = jax.random.randint(r_a, (num_samples,), 0, A)
+        with jax.named_scope("next_state"):
+            x_next = jax.random.categorical(r_n, jnp.log(P[x, a] + 1e-30),
+                                            axis=-1)
+        with jax.named_scope("target"):
+            targets = (c[x] + env_params["gamma"] * params["v"][x_next]
+                       + params["noise_scale"]
+                       * jax.random.normal(r_t, (num_samples,)))
+        with jax.named_scope("features"):
+            return jax.nn.one_hot(x, S), targets
 
     return fn
 

@@ -126,13 +126,21 @@ class LinearSystem:
         sig = jnp.sqrt(self.noise_var)
 
         def fn(params, rng):
+            # each stage under a named scope, which names its ops in the
+            # compiled program and the device trace
             r_x, r_w = jax.random.split(rng)
-            x = jax.random.uniform(r_x, (num_samples, 2))
-            noise = sig * params["noise_scale"] * jax.random.normal(r_w, (num_samples, 2))
-            x_next = x @ A.T + noise
-            cost = jnp.sum(x**2, axis=-1)
-            targets = cost + self.gamma * poly_features(x_next) @ params["v"]
-            return poly_features(x), targets
+            with jax.named_scope("state_draw"):
+                x = jax.random.uniform(r_x, (num_samples, 2))
+            with jax.named_scope("next_state"):
+                noise = (sig * params["noise_scale"]
+                         * jax.random.normal(r_w, (num_samples, 2)))
+                x_next = x @ A.T + noise
+            with jax.named_scope("target"):
+                cost = jnp.sum(x**2, axis=-1)
+                targets = (cost
+                           + self.gamma * poly_features(x_next) @ params["v"])
+            with jax.named_scope("features"):
+                return poly_features(x), targets
 
         return fn
 

@@ -34,6 +34,7 @@ is committed (refusing while the ``INCOMPLETE`` resume lock exists).
 from __future__ import annotations
 
 import concurrent.futures
+import contextvars
 import dataclasses
 import functools
 import hashlib
@@ -46,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import faults
+from repro import faults, spans
 from repro.checkpoint import store as ckpt
 from repro.core.algorithm1 import InnerTrace, ProblemTerms, SummaryTrace
 from repro.core import vfa as vfa_lib
@@ -231,6 +232,7 @@ def completed_chunks(store_dir: str, exec_hash: str) -> dict[int, str]:
     return out
 
 
+@spans.span("runtime.run")
 def run_sweep_resumable(
     spec: SweepSpec,
     sampler,
@@ -282,10 +284,17 @@ def run_sweep_resumable(
     While the sweep runs (and after a crash) the dir carries an
     ``INCOMPLETE`` marker, removed only on successful completion — the
     resume lock ``gc_finished`` refuses to collect past.
+
+    The call's host work is recorded as the span ``runtime.run`` with
+    children ``sweep.plan``, one ``runtime.segment`` per segment dispatched
+    or restored (``restored``), ``runtime.checkpoint`` per chunk written
+    (on the writer thread, under the caller's call id) and
+    ``runtime.drain``, the final wait on pending writes (``repro.spans``).
     """
-    plan = plan_sweep(spec, sampler, w0, problem, param_sets=param_sets,
-                      env_sets=env_sets, fleet_sets=fleet_sets, mesh=mesh,
-                      state_init_fn=state_init_fn)
+    with spans.span("sweep.plan"):
+        plan = plan_sweep(spec, sampler, w0, problem, param_sets=param_sets,
+                          env_sets=env_sets, fleet_sets=fleet_sets,
+                          mesh=mesh, state_init_fn=state_init_fn)
     sh = store_lib.spec_hash(spec)
     in_digest = inputs_digest(sampler, w0, problem=problem,
                               param_sets=param_sets, env_sets=env_sets,
@@ -323,16 +332,17 @@ def run_sweep_resumable(
         # Runs on the writer thread: np.asarray blocks until the device
         # finishes this segment, while the main thread has already
         # dispatched the next one — checkpoint I/O overlaps execution.
-        host = jax.tree.map(np.asarray, out)
-        ckpt.save(path, host, durable=durable, metadata={
-            "exec_hash": exec_hash, "spec_hash": sh,
-            "inputs_digest": in_digest, "segment_index": index,
-            "segment": list(segments[index]),
-            "grid_coords": {"start": segments[index][0],
-                            "stop": segments[index][1],
-                            "axes": list(plan.axes),
-                            "grid_shape": list(plan.gs)},
-        })
+        with spans.span("runtime.checkpoint", index=index):
+            host = jax.tree.map(np.asarray, out)
+            ckpt.save(path, host, durable=durable, metadata={
+                "exec_hash": exec_hash, "spec_hash": sh,
+                "inputs_digest": in_digest, "segment_index": index,
+                "segment": list(segments[index]),
+                "grid_coords": {"start": segments[index][0],
+                                "stop": segments[index][1],
+                                "axes": list(plan.axes),
+                                "grid_shape": list(plan.gs)},
+            })
 
     # Segment results accumulate in place into one run-stacked pytree: the
     # accumulator is DONATED to the scatter at every segment boundary (XLA
@@ -346,40 +356,47 @@ def run_sweep_resumable(
             max_workers=1, thread_name_prefix="sweep-ckpt") as pool:
         pending = []
         for i, (a, b) in enumerate(segments):
-            seg = None
-            if i in done:
-                try:
-                    restored, meta = ckpt.restore(done[i], template)
-                except ckpt.CorruptCheckpointError as e:
-                    # checksum/decode failure on a finished chunk: rename
-                    # it aside and recompute the segment — the resumed
-                    # result stays bitwise identical to a clean run, and
-                    # corrupt bytes are never merged
-                    faults.quarantine_path(done[i], str(e))
-                    del done[i]
-                else:
-                    if tuple(meta["segment"]) != (a, b):
-                        raise ValueError(
-                            f"chunk {done[i]} covers runs {meta['segment']}, "
-                            f"expected [{a}, {b}) — stale store_dir?")
-                    seg = restored
+            with spans.span("runtime.segment", index=i) as attrs:
+                seg = None
+                if i in done:
+                    try:
+                        restored, meta = ckpt.restore(done[i], template)
+                    except ckpt.CorruptCheckpointError as e:
+                        # checksum/decode failure on a finished chunk:
+                        # rename it aside and recompute the segment — the
+                        # resumed result stays bitwise identical to a clean
+                        # run, and corrupt bytes are never merged
+                        faults.quarantine_path(done[i], str(e))
+                        del done[i]
+                    else:
+                        if tuple(meta["segment"]) != (a, b):
+                            raise ValueError(
+                                f"chunk {done[i]} covers runs "
+                                f"{meta['segment']}, expected [{a}, {b}) — "
+                                f"stale store_dir?")
+                        seg = restored
+                        if on_chunk is not None:
+                            on_chunk(i, len(segments), True)
+                attrs["restored"] = seg is not None
+                if seg is None:
+                    seg = exec_plan_segment(plan, a, b)   # async dispatch
+                    # the writer closure holds the only other reference to
+                    # seg; it is submitted BEFORE the scatter so the
+                    # checkpoint bytes are fetched from the segment output,
+                    # never from acc.  The copied context carries this
+                    # call's span into the writer thread.
+                    pending.append(pool.submit(
+                        contextvars.copy_context().run, _save_chunk,
+                        _chunk_path(store_dir, i), i, seg))
                     if on_chunk is not None:
-                        on_chunk(i, len(segments), True)
-            if seg is None:
-                seg = exec_plan_segment(plan, a, b)   # async dispatch
-                # the writer closure holds the only other reference to seg;
-                # it is submitted BEFORE the scatter so the checkpoint bytes
-                # are fetched from the segment output, never from acc
-                pending.append(pool.submit(
-                    _save_chunk, _chunk_path(store_dir, i), i, seg))
-                if on_chunk is not None:
-                    on_chunk(i, len(segments), False)
-            if acc is None:
-                single = seg
-            else:
-                acc = _scatter_segment(acc, seg, jnp.int32(a))
-        for f in pending:
-            f.result()                                 # re-raise I/O errors
+                        on_chunk(i, len(segments), False)
+                if acc is None:
+                    single = seg
+                else:
+                    acc = _scatter_segment(acc, seg, jnp.int32(a))
+        with spans.span("runtime.drain", pending=len(pending)):
+            for f in pending:
+                f.result()                             # re-raise I/O errors
 
     flat = single if acc is None else acc
     result = finalize_sweep(plan, flat)

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
+from repro import spans
 from repro.core import channel as channel_lib
 from repro.core import gain_dispatch
 from repro.core import vfa as vfa_lib
@@ -659,15 +660,31 @@ def run_sweep(
     Returns a SweepResult whose leaves carry the grid shape
     ``([E,] [P,] M, L, R, S)`` and whose ``axes`` names those axes.
 
+    The call's host work is recorded as the span ``sweep.run`` (``runs``,
+    ``padded_runs``, ``devices`` and the ``step`` path) with children
+    ``sweep.plan``, ``sweep.dispatch`` (until the executor returns its
+    unfinished arrays) and ``sweep.finalize`` (``repro.spans``).
+
     Checkpointable execution of the same grid: ``repro.experiments.runtime
     .run_sweep_resumable`` runs the identical plan segment by segment,
     persisting each completed segment, and reassembles the bit-identical
     ``SweepResult`` after a crash.
     """
-    plan = plan_sweep(spec, sampler, w0, problem, param_sets=param_sets,
-                      env_sets=env_sets, fleet_sets=fleet_sets, mesh=mesh,
-                      state_init_fn=state_init_fn)
-    return finalize_sweep(plan, exec_plan(plan))
+    with spans.span("sweep.run") as attrs:
+        with spans.span("sweep.plan"):
+            plan = plan_sweep(spec, sampler, w0, problem,
+                              param_sets=param_sets, env_sets=env_sets,
+                              fleet_sets=fleet_sets, mesh=mesh,
+                              state_init_fn=state_init_fn)
+        step = gain_dispatch._resolve_step(spec.step_backend)
+        attrs.update(runs=plan.num_runs, padded_runs=plan.padded_runs,
+                     devices=plan.num_devices,
+                     step=(step if spec.channel_sets is None
+                           else f"{step}+channel"))
+        with spans.span("sweep.dispatch"):
+            flat = exec_plan(plan)
+        with spans.span("sweep.finalize"):
+            return finalize_sweep(plan, flat)
 
 
 def tradeoff_rows(result: SweepResult, spec: SweepSpec, **extra) -> list[dict]:

@@ -244,6 +244,7 @@ def gain_family_stats(phi: Array, g: Array,
         cols = 4
     out = pl.pallas_call(
         functools.partial(_family_kernel, with_model),
+        name="gain_family_stats",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, cols), lambda ai, ti, ni: (ai, 0)),
@@ -471,6 +472,7 @@ def megastep_call(phi: Array, g: Array, w: Array, ctl: Array,
     w_next, alphas, gains = pl.pallas_call(
         functools.partial(_megastep_kernel, with_model, with_deliver,
                           eps, T, m, bm),
+        name="megastep",
         grid=grid,
         in_specs=in_specs,
         out_specs=[run_row, agent_col, agent_col],
