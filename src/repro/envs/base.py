@@ -97,6 +97,22 @@ def as_param_sampler(env: Env, v_current, num_agents: int,
     )
 
 
+def table_select(table: Array, idx: Array) -> Array:
+    """``table[idx]`` for 1-D ``table`` and ``idx``, as a compare-and-select
+    over the entries: a gather runs element by element on the TPU, the
+    select on its vector unit.  The chosen entry's bits are OR-ed with zeros
+    (no float arithmetic), so each output is its entry bit for bit, whatever
+    the entry (signed zero, subnormal, inf, NaN), and an entry not chosen
+    never reaches it.  The entries run along the leading axis, so the reduce
+    runs across vector registers."""
+    bits = jax.lax.bitcast_convert_type(
+        table, jnp.dtype(f"uint{8 * table.dtype.itemsize}"))
+    hit = jnp.arange(table.shape[0])[:, None] == idx[None, :]
+    picked = jax.lax.reduce(jnp.where(hit, bits[:, None], 0),
+                            bits.dtype.type(0), jax.lax.bitwise_or, (0,))
+    return jax.lax.bitcast_convert_type(picked, table.dtype)
+
+
 def family_sampler_fn(num_samples: int):
     """Tabular sampling with the ENV as data: one fn for a whole MDP family.
 
@@ -123,7 +139,9 @@ def family_sampler_fn(num_samples: int):
             x_next = jax.random.categorical(r_n, jnp.log(P[x, a] + 1e-30),
                                             axis=-1)
         with jax.named_scope("target"):
-            targets = (c[x] + env_params["gamma"] * params["v"][x_next]
+            v = params["v"]
+            targets = (table_select(c, x)
+                       + env_params["gamma"] * table_select(v, x_next)
                        + params["noise_scale"]
                        * jax.random.normal(r_t, (num_samples,)))
         with jax.named_scope("features"):

@@ -1,11 +1,16 @@
 """Environment correctness: transition kernels, exact values, closed forms."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.envs import GridWorld, LinearSystem
+from repro.envs import GarnetMDP, GridWorld, LinearSystem
+from repro.envs.base import (family_sampler_fn, stack_agent_params,
+                             stack_env_family, stack_env_fleets,
+                             table_select)
 from repro.envs.linear_system import poly_features
 
 
@@ -86,3 +91,117 @@ def test_linear_system_sampler_features(key):
     assert phi_t.shape == (1000, 6)
     np.testing.assert_allclose(np.asarray(phi_t)[:, 5], 1.0)  # bias feature
     assert np.all(np.asarray(targets) >= 0)  # c(x) >= 0 and V_cur = 0
+
+
+# The tabular sampler's target reads c[x] and v[x'] by an exact select
+# (``table_select``); these tests hold it bit for bit to the gather form.
+
+T_SAMPLES = 8
+
+
+def _gather_sampler(num_samples):
+    """``family_sampler_fn`` with both table lookups of the target written
+    as gathers; also returns the next states."""
+    def fn(env_params, params, rng):
+        P, c = env_params["P"], env_params["c"]
+        r_x, r_a, r_n, r_t = jax.random.split(rng, 4)
+        x = jax.random.categorical(r_x, params["visit_logits"],
+                                   shape=(num_samples,))
+        a = jax.random.randint(r_a, (num_samples,), 0, P.shape[1])
+        x_next = jax.random.categorical(r_n, jnp.log(P[x, a] + 1e-30),
+                                        axis=-1)
+        targets = (c[x] + env_params["gamma"] * params["v"][x_next]
+                   + params["noise_scale"]
+                   * jax.random.normal(r_t, (num_samples,)))
+        return jax.nn.one_hot(x, P.shape[0]), targets, x_next
+    return fn
+
+
+def _fleet(env, v):
+    """Three agents: uniform visits, skewed visits, and target noise."""
+    S = env.num_states
+    skew = np.zeros(S, np.float32)
+    skew[S // 2] = 3.0
+    return stack_agent_params(
+        env.agent_param_row(v),
+        env.agent_param_row(v, visit_logits=jnp.asarray(skew)),
+        env.agent_param_row(v, noise_scale=0.5))
+
+
+def _sampler_case(name):
+    """``(program, gather, args)``: the program's sampler as its caller runs
+    it and the gather form, both vmapped over agents (and envs), with a
+    ``v`` of negative entries."""
+    if name == "family3":
+        envs = [GarnetMDP(seed=s) for s in range(3)]
+        v = np.linspace(-1.0, 1.0, envs[0].num_states).astype(np.float32)
+        fam = stack_env_family(envs, v, with_terms=False)
+        fleets = stack_env_fleets([_fleet(e, v) for e in envs])
+        keys = jax.random.split(jax.random.key(3), (3, 3))
+        program = jax.vmap(jax.vmap(family_sampler_fn(T_SAMPLES),
+                                    (None, 0, 0)))
+        gather = jax.vmap(jax.vmap(_gather_sampler(T_SAMPLES), (None, 0, 0)))
+        return program, gather, (fam.params, fleets, keys)
+    env = {"garnet20": GarnetMDP(num_states=20),
+           "garnet128": GarnetMDP(num_states=128),
+           "gridworld": GridWorld()}[name]
+    v = np.linspace(-1.0, 1.0, env.num_states).astype(np.float32)
+    keys = jax.random.split(jax.random.key(1), 3)
+    program = jax.vmap(env.sampler_fn(T_SAMPLES))
+    gather = jax.vmap(partial(_gather_sampler(T_SAMPLES), env.env_params()))
+    return program, gather, (_fleet(env, v), keys)
+
+
+SAMPLER_CASES = ["garnet20", "garnet128", "gridworld", "family3"]
+
+
+def _with_nan_in_unselected_lane(args, x_next):
+    """``args`` with NaN in the lane of each agent's ``v`` that is the first
+    state none of that agent's samples reads as its next state."""
+    *rest, fleet, keys = args
+    v = np.array(fleet["v"])
+    reads = np.asarray(x_next)
+    for row, read in zip(v.reshape(-1, v.shape[-1]),
+                         reads.reshape(-1, reads.shape[-1])):
+        row[np.setdiff1d(np.arange(row.size), read)[0]] = np.nan
+    return (*rest, dict(fleet, v=jnp.asarray(v)), keys)
+
+
+@pytest.mark.parametrize("case", SAMPLER_CASES)
+def test_tabular_sampler_targets_bitwise_equal_gather_form(case):
+    program, gather, args = _sampler_case(case)
+    args = _with_nan_in_unselected_lane(args, gather(*args)[2])
+    phi, targets = jax.jit(program)(*args)
+    phi_ref, targets_ref, _ = jax.jit(gather)(*args)
+    targets, targets_ref = np.asarray(targets), np.asarray(targets_ref)
+    assert not np.isnan(targets).any()
+    assert np.array_equal(targets, targets_ref)
+    assert np.array_equal(targets.view(np.uint32),
+                          targets_ref.view(np.uint32))
+    assert np.array_equal(np.asarray(phi), np.asarray(phi_ref))
+
+
+@pytest.mark.parametrize("case", SAMPLER_CASES)
+def test_tabular_sampler_gathers_only_the_transition_row(case):
+    """Of the sampler's table reads only ``P[x, a]`` stays a gather: the
+    target's ``c[x]`` and ``v[x']`` lower to selects."""
+    program, gather, args = _sampler_case(case)
+
+    def count(fn):
+        text = jax.jit(fn).lower(*args).as_text()
+        return sum('"stablehlo.gather"(' in line
+                   for line in text.splitlines())
+
+    assert count(gather) == 3
+    assert count(program) == 1
+
+
+def test_table_select_is_the_entry_bit_for_bit():
+    """Every lane chosen once, over signed zeros, infinities, NaN, a
+    subnormal and negatives."""
+    table = jnp.asarray([-0.0, 0.0, np.inf, -np.inf, np.nan, 1e-45, -2.5,
+                         3.0], jnp.float32)
+    idx = jnp.asarray([7, 6, 5, 4, 3, 2, 1, 0, 0, 4], jnp.int32)
+    got = np.asarray(jax.jit(table_select)(table, idx))
+    want = np.asarray(table)[np.asarray(idx)]
+    assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
