@@ -113,6 +113,41 @@ def table_select(table: Array, idx: Array) -> Array:
     return jax.lax.bitcast_convert_type(picked, table.dtype)
 
 
+def cdf_table(probs: Array) -> Array:
+    """Cumulative table of the categoricals ``probs`` (states on the last
+    axis) for ``inverse_cdf_draw``, in float32.
+
+    A zero-probability state's entry repeats the entry before it (``-inf``
+    before the first positive state), so its interval ``[cdf[s-1], cdf[s])``
+    is empty however the cumulative sum was associated; every entry from
+    the last positive state onward is ``+inf``, so a row total that falls
+    short of 1 in float32 gives the tail ``[total, 1)`` to the last state
+    that can occur, and a trailing zero state is never reached."""
+    probs = jnp.asarray(probs, jnp.float32)
+    lane = jnp.arange(probs.shape[-1])
+    positive = probs > 0
+    last = jnp.max(jnp.where(positive, lane, -1), axis=-1, keepdims=True)
+    cdf = jax.lax.cummax(jnp.where(positive, jnp.cumsum(probs, axis=-1),
+                                   -jnp.inf), axis=probs.ndim - 1)
+    return jnp.where(lane >= last, jnp.inf, cdf)
+
+
+def inverse_cdf_draw(key: Array, cdf: Array, shape=None) -> Array:
+    """Int32 states drawn by inverse CDF: one float32 uniform ``u`` per
+    sample, state ``#{s : cdf[..., s] <= u}`` (a compare and a sum over the
+    S lanes of ``cdf_table``'s rows; no argmax, no log).
+
+    ``shape`` is the batch shape of the draw (default ``cdf.shape[:-1]``);
+    the rows of ``cdf`` broadcast against it, so samples that share a
+    distribution share one row.  The sampler is exact up to float32
+    rounding: the uniform resolves each state's probability to within
+    2^-23, its granularity, the order of the Gumbel-max form's own float32
+    error, with one random number per sample instead of S."""
+    shape = cdf.shape[:-1] if shape is None else tuple(shape)
+    u = jax.random.uniform(key, shape, jnp.float32)
+    return jnp.sum(cdf <= u[..., None], axis=-1, dtype=jnp.int32)
+
+
 def family_sampler_fn(num_samples: int):
     """Tabular sampling with the ENV as data: one fn for a whole MDP family.
 
@@ -131,13 +166,12 @@ def family_sampler_fn(num_samples: int):
         S, A = P.shape[0], P.shape[1]
         r_x, r_a, r_n, r_t = jax.random.split(rng, 4)
         with jax.named_scope("state_draw"):
-            x = jax.random.categorical(r_x, params["visit_logits"],
-                                       shape=(num_samples,))
+            visit = cdf_table(jax.nn.softmax(params["visit_logits"]))
+            x = inverse_cdf_draw(r_x, visit, (num_samples,))
         with jax.named_scope("action_draw"):
             a = jax.random.randint(r_a, (num_samples,), 0, A)
         with jax.named_scope("next_state"):
-            x_next = jax.random.categorical(r_n, jnp.log(P[x, a] + 1e-30),
-                                            axis=-1)
+            x_next = inverse_cdf_draw(r_n, cdf_table(P)[x, a])
         with jax.named_scope("target"):
             v = params["v"]
             targets = (table_select(c, x)

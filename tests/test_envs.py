@@ -1,14 +1,18 @@
 """Environment correctness: transition kernels, exact values, closed forms."""
 
+import math
+import re
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from scipy import stats
 
 from repro.envs import GarnetMDP, GridWorld, LinearSystem
-from repro.envs.base import (family_sampler_fn, stack_agent_params,
+from repro.envs.base import (cdf_table, family_sampler_fn,
+                             inverse_cdf_draw, stack_agent_params,
                              stack_env_family, stack_env_fleets,
                              table_select)
 from repro.envs.linear_system import poly_features
@@ -101,15 +105,15 @@ T_SAMPLES = 8
 
 def _gather_sampler(num_samples):
     """``family_sampler_fn`` with both table lookups of the target written
-    as gathers; also returns the next states."""
+    as gathers; also returns the next states.  The states are drawn as the
+    program draws them, by inverse CDF on the same keys."""
     def fn(env_params, params, rng):
         P, c = env_params["P"], env_params["c"]
         r_x, r_a, r_n, r_t = jax.random.split(rng, 4)
-        x = jax.random.categorical(r_x, params["visit_logits"],
-                                   shape=(num_samples,))
+        visit = cdf_table(jax.nn.softmax(params["visit_logits"]))
+        x = inverse_cdf_draw(r_x, visit, (num_samples,))
         a = jax.random.randint(r_a, (num_samples,), 0, P.shape[1])
-        x_next = jax.random.categorical(r_n, jnp.log(P[x, a] + 1e-30),
-                                        axis=-1)
+        x_next = inverse_cdf_draw(r_n, cdf_table(P)[x, a])
         targets = (c[x] + env_params["gamma"] * params["v"][x_next]
                    + params["noise_scale"]
                    * jax.random.normal(r_t, (num_samples,)))
@@ -205,3 +209,116 @@ def test_table_select_is_the_entry_bit_for_bit():
     got = np.asarray(jax.jit(table_select)(table, idx))
     want = np.asarray(table)[np.asarray(idx)]
     assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+def _random_bit_sizes(text):
+    """Element counts of every threefry block in lowered program text."""
+    sizes = []
+    for line in text.splitlines():
+        if "call @threefry2x32" in line:
+            for dims in re.findall(r"tensor<([0-9x]+)xui32>",
+                                   line.split("->")[-1]):
+                sizes.append(math.prod(int(d) for d in dims.split("x")))
+    return sizes
+
+
+@pytest.mark.parametrize("case", ["garnet128", "family3"])
+def test_tabular_sampler_random_bits_grow_with_samples_not_states(case):
+    """The sampler draws one uniform per sample and draw: no block of
+    random bits has T * S entries, as a Gumbel-max categorical's has."""
+    program, _, args = _sampler_case(case)
+    keys = args[-1]
+    S = args[-2]["v"].shape[-1]
+    sizes = _random_bit_sizes(jax.jit(program).lower(*args).as_text())
+    assert sizes and max(sizes) == keys.size * T_SAMPLES
+    gumbel = jax.jit(lambda k: jax.random.categorical(
+        k, jnp.zeros(S), shape=(T_SAMPLES,)))
+    assert max(_random_bit_sizes(
+        gumbel.lower(jax.random.key(0)).as_text())) == T_SAMPLES * S
+
+
+N_DRAWS = 1 << 20
+
+
+def _draw(probs, seed=0):
+    """``N_DRAWS`` states from one row of probabilities, as counts."""
+    cdf = cdf_table(jnp.asarray(probs, jnp.float32))
+    x = jax.jit(partial(inverse_cdf_draw, shape=(N_DRAWS,)))(
+        jax.random.key(seed), cdf)
+    assert x.dtype == jnp.int32
+    return np.bincount(np.asarray(x), minlength=len(probs))
+
+
+_GEOMETRIC = [2.0 ** -k for k in range(1, 11)]   # total 1 - 2^-10, exact
+
+ZERO_ROWS = {
+    "zeros_at_start": [0.0, 0.0, 0.5, 0.25, 0.25],
+    "zeros_in_middle": [0.3, 0.0, 0.0, 0.4, 0.0, 0.3],
+    "zeros_at_end": [0.25, 0.75, 0.0, 0.0, 0.0],
+    "total_below_one": _GEOMETRIC + [0.0, 0.0],
+    "rounded_below_one": [np.float32(1 / 12)] * 12,
+    "neg_inf_visit_logits": np.asarray(jax.nn.softmax(jnp.asarray(
+        [-np.inf, 0.0, -np.inf, 1.0, 0.5, -np.inf, -np.inf, -np.inf]))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ZERO_ROWS))
+def test_inverse_cdf_draw_never_returns_a_zero_probability_state(case):
+    probs = np.asarray(ZERO_ROWS[case], np.float32)
+    counts = _draw(probs)
+    assert counts.size == probs.size          # no state past the row
+    np.testing.assert_array_equal(counts > 0, probs > 0)
+
+
+def test_inverse_cdf_draw_gives_the_tail_below_one_to_the_last_state():
+    """A row summing to 1 - 2^-10: the last positive state takes its own
+    2^-10 and the missing 2^-10, not a trailing zero state."""
+    counts = _draw(np.asarray(_GEOMETRIC + [0.0, 0.0], np.float32))
+    expect = N_DRAWS * 2.0 ** -9
+    assert abs(counts[9] - expect) < 5 * math.sqrt(expect)
+    assert counts[10:].sum() == 0
+
+
+def _chi_square_rows():
+    garnet = GarnetMDP(num_states=128)
+    P = np.asarray(garnet.env_params()["P"])
+    gw = GridWorld()
+    P_gw = np.asarray(gw.env_params()["P"])
+    skew = _fleet(garnet, np.zeros(128, np.float32))["visit_logits"][1]
+    return {
+        "garnet128_row": P[0, 0],
+        "garnet128_row_last": P[127, 3],
+        "gridworld_windy_row": P_gw[gw._idx(0, 1), 2],
+        "skewed_visits": np.asarray(jax.nn.softmax(skew)),
+    }
+
+
+@pytest.mark.parametrize("case", ["garnet128_row", "garnet128_row_last",
+                                  "gridworld_windy_row", "skewed_visits"])
+def test_inverse_cdf_draw_matches_the_distribution(case):
+    probs = np.asarray(_chi_square_rows()[case], np.float32)
+    support = probs > 0
+    assert support.sum() > 1
+    counts = _draw(probs, seed=17)
+    assert counts[~support].sum() == 0
+    p = probs[support].astype(np.float64)
+    _, p_value = stats.chisquare(counts[support], N_DRAWS * p / p.sum())
+    assert p_value > 1e-3
+
+
+def test_inverse_cdf_draw_is_int32_of_the_batch_shape_under_vmap():
+    """A row shared by T samples and the same row gathered per sample draw
+    the same int32 states on the same keys."""
+    agents, S = 4, 16
+    probs = jax.random.dirichlet(jax.random.key(5), jnp.ones(S), (agents,))
+    cdf = cdf_table(probs)
+    keys = jax.random.split(jax.random.key(6), agents)
+    shared = jax.vmap(partial(inverse_cdf_draw, shape=(T_SAMPLES,)))(
+        keys, cdf)
+    rows = jnp.broadcast_to(cdf[:, None], (agents, T_SAMPLES, S))
+    gathered = jax.vmap(inverse_cdf_draw)(keys, rows)
+    for x in (shared, gathered):
+        assert x.dtype == jnp.int32
+        assert x.shape == (agents, T_SAMPLES)
+    assert np.array_equal(np.asarray(shared), np.asarray(gathered))
+    assert 0 <= int(shared.min()) and int(shared.max()) < S
